@@ -480,15 +480,18 @@ class Balancer:
         carry ``spec.pad_part`` in ``old_parts``.  ``keys`` short-circuits
         the keys stage with precomputed (cached) SFC keys; ``warm`` seeds
         the k-section search boxes with a previous step's splitters."""
+        # named for the program it becomes under jax.jit: jit_balance
         if self.spec.backend == "sharded":
-            def fn(weights, coords, old_parts=None, keys=None, warm=None):
+            def balance(weights, coords, old_parts=None, keys=None,
+                        warm=None):
                 return self._sharded_apply(weights, coords, old_parts,
                                            keys, warm)
         else:
-            def fn(weights, coords, old_parts=None, keys=None, warm=None):
+            def balance(weights, coords, old_parts=None, keys=None,
+                        warm=None):
                 return self._host_pipeline(weights, coords, old_parts,
                                            keys, warm)
-        return fn
+        return balance
 
     def _host_pipeline(self, weights, coords, old_parts, pre_keys=None,
                        warm=None) -> BalanceResult:

@@ -108,12 +108,6 @@ class StepStats:
     # surface index the halo bytes scale with
     comm_psum_bytes: int = 0
     comm_halo_bytes: int = 0
-    # split-matvec phase model (owned layout, recorded under tracing):
-    # interface pass + halo exchange vs the interior pass that hides it
-    # (fem.parallel.measure_matvec_phases -- interior >> halo means the
-    # exchange is fully latency-hidden)
-    t_matvec_interior: float = 0.0
-    t_matvec_halo: float = 0.0
 
 
 @dataclass
@@ -369,8 +363,6 @@ class SessionState:
     cut: Optional[int] = None           # surface index of current partition
     comm_psum_bytes: int = 0            # per-matvec comm model (owned)
     comm_halo_bytes: int = 0
-    t_matvec_interior: float = 0.0      # split-matvec phase model (owned,
-    t_matvec_halo: float = 0.0          # measured under tracing only)
     timings: Dict[str, float] = field(default_factory=dict)
 
     @property
@@ -445,38 +437,43 @@ def _pack_owned(session: "AdaptiveSession", state: SessionState):
     staleness repack go through here."""
     from .halo import build_halo_plan, publish_wire_model, update_halo_plan
     from .parallel import shard_elements_on_device
-    el = _ensure_elements(state)
-    mesh = state.mesh
-    parts = np.asarray(mesh.leaf_payload["parts"])
-    p = session.balance_spec.p
-    if (session.spec.incremental and state.halo is not None
-            and state.packed_tets is not None
-            and state.packed_parts is not None):
-        plan, hinfo = update_halo_plan(
-            state.halo, state.packed_tets, state.packed_parts,
-            mesh.tets, parts, mesh.n_verts, p)
-    else:
-        plan = build_halo_plan(mesh.tets, parts, mesh.n_verts, p)
-        hinfo = {"mode": "scratch"}
-    state.halo = plan
-    state.halo_info = hinfo
-    state.packed_tets = mesh.tets.copy()
-    state.packed_parts = parts.copy()
-    state.sharded = shard_elements_on_device(
-        el, jnp.asarray(parts), p, session.device_mesh, halo=plan)
-    state.packed_ntets = mesh.n_tets
-    state.packed_version = state.mesh_version
-    state.owned_ops = {}
-    state.cut = int(cut_links(jnp.asarray(parts),
-                              jnp.asarray(mesh.face_adjacency())))
-    # wire bytes in the solve's actual scalar width (f32 by default, f64
-    # under jax_enable_x64) -- the element arrays carry that dtype
-    itemsize = int(np.dtype(el.vol.dtype).itemsize)
-    state.comm_psum_bytes = plan.psum_bytes(itemsize)
-    state.comm_halo_bytes = plan.halo_bytes(itemsize)
-    tr = telemetry.get_tracer()
-    if tr.enabled:
-        publish_wire_model(plan, tr.metrics, itemsize=itemsize)
+    with telemetry.span("adapt/pack", step=state.step):
+        el = _ensure_elements(state)
+        mesh = state.mesh
+        parts = np.asarray(mesh.leaf_payload["parts"])
+        p = session.balance_spec.p
+        step = state.step
+        with telemetry.span("adapt/pack/halo_plan", step=step):
+            if (session.spec.incremental and state.halo is not None
+                    and state.packed_tets is not None
+                    and state.packed_parts is not None):
+                plan, hinfo = update_halo_plan(
+                    state.halo, state.packed_tets, state.packed_parts,
+                    mesh.tets, parts, mesh.n_verts, p)
+            else:
+                plan = build_halo_plan(mesh.tets, parts, mesh.n_verts, p)
+                hinfo = {"mode": "scratch"}
+        state.halo = plan
+        state.halo_info = hinfo
+        state.packed_tets = mesh.tets.copy()
+        state.packed_parts = parts.copy()
+        with telemetry.span("adapt/pack/migrate", step=step):
+            state.sharded = shard_elements_on_device(
+                el, jnp.asarray(parts), p, session.device_mesh, halo=plan)
+        state.packed_ntets = mesh.n_tets
+        state.packed_version = state.mesh_version
+        state.owned_ops = {}
+        with telemetry.span("adapt/pack/cut", step=step):
+            state.cut = int(cut_links(jnp.asarray(parts),
+                                      jnp.asarray(mesh.face_adjacency())))
+        # wire bytes in the solve's actual scalar width (f32 by default, f64
+        # under jax_enable_x64) -- the element arrays carry that dtype
+        itemsize = int(np.dtype(el.vol.dtype).itemsize)
+        state.comm_psum_bytes = plan.psum_bytes(itemsize)
+        state.comm_halo_bytes = plan.halo_bytes(itemsize)
+        tr = telemetry.get_tracer()
+        if tr.enabled:
+            publish_wire_model(plan, tr.metrics, itemsize=itemsize)
 
 
 def _ensure_owned_packing(session: "AdaptiveSession", state: SessionState):
@@ -511,30 +508,48 @@ def _owned_operators(session: "AdaptiveSession", state: SessionState,
     if ops is None:
         # kernel selection reuses PR 4's variant seam: the nested
         # BalanceSpec's use_pallas flag (None = auto on TPU)
-        ops = make_owned_operators(
-            state.sharded, session.device_mesh, c,
-            use_pallas=session.balance_spec.use_pallas)
+        with telemetry.span("adapt/solve/operators", step=state.step):
+            ops = make_owned_operators(
+                state.sharded, session.device_mesh, c,
+                use_pallas=session.balance_spec.use_pallas)
         state.owned_ops[c] = ops
     return ops
+
+
+def _solve_owned(session: "AdaptiveSession", state: SessionState,
+                 rhs_g_free: Callable, c: float):
+    """The owned solve stages' shared tail: assemble the (n_verts,) load
+    vector, boundary values and free mask with ``rhs_g_free(el, verts)``,
+    run the distributed PCG, and wait for its solution -- each phase under
+    its own span."""
+    from .parallel import sharded_solve_dirichlet
+    step = state.step
+    el = _ensure_elements(state)
+    _ensure_owned_packing(session, state)
+    with telemetry.span("adapt/solve/assemble", step=step):
+        rhs, g, free = rhs_g_free(el, jnp.asarray(state.mesh.verts))
+    ops = _owned_operators(session, state, c)
+    with telemetry.span("adapt/solve/pcg", step=step):
+        sol = sharded_solve_dirichlet(
+            state.sharded, session.device_mesh, rhs, g, free, c,
+            tol=session.spec.tol, maxiter=session.spec.maxiter,
+            operators=ops)
+    with telemetry.span("adapt/solve/wait", step=step):
+        state.u = jax.block_until_ready(sol.x)
+        state.cg_iters = int(sol.iters)
 
 
 @register_adapt_stage("solve", "stationary_owned")
 def _solve_stationary_owned(session: "AdaptiveSession", state: SessionState):
     """Stationary solve on owned vertices: distributed PCG whose matvec
     communicates via the halo exchange (no vertex-sized psum)."""
-    from .parallel import sharded_solve_dirichlet
     prob = session.problem
-    el = _ensure_elements(state)
-    _ensure_owned_packing(session, state)
-    verts = jnp.asarray(state.mesh.verts)
-    rhs = load_vector(el, verts, prob.f)
-    sol = sharded_solve_dirichlet(
-        state.sharded, session.device_mesh, rhs, prob.exact(verts),
-        _free_mask(state.mesh), prob.c, tol=session.spec.tol,
-        maxiter=session.spec.maxiter,
-        operators=_owned_operators(session, state, prob.c))
-    state.u = jax.block_until_ready(sol.x)
-    state.cg_iters = int(sol.iters)
+
+    def rhs_g_free(el, verts):
+        return (load_vector(el, verts, prob.f), prob.exact(verts),
+                _free_mask(state.mesh))
+
+    _solve_owned(session, state, rhs_g_free, prob.c)
 
 
 @register_adapt_stage("solve", "backward_euler_owned")
@@ -542,22 +557,16 @@ def _solve_backward_euler_owned(session: "AdaptiveSession",
                                 state: SessionState):
     """Backward-Euler step on owned vertices (same system as the
     replicated variant, halo-exchange matvec)."""
-    from .parallel import sharded_solve_dirichlet
     prob = session.problem
-    spec = session.spec
-    t_next = state.t + spec.dt
-    el = _ensure_elements(state)
-    _ensure_owned_packing(session, state)
-    verts = jnp.asarray(state.mesh.verts)
-    fv = load_vector(el, verts, lambda x: prob.f(x, t_next))
-    rhs = mass_matvec(el, jnp.asarray(state.u)) / spec.dt + fv
-    c = 1.0 / spec.dt
-    sol = sharded_solve_dirichlet(
-        state.sharded, session.device_mesh, rhs, prob.exact(verts, t_next),
-        _free_mask(state.mesh), c, tol=spec.tol, maxiter=spec.maxiter,
-        operators=_owned_operators(session, state, c))
-    state.u = jax.block_until_ready(sol.x)
-    state.cg_iters = int(sol.iters)
+    dt = session.spec.dt
+    t_next = state.t + dt
+
+    def rhs_g_free(el, verts):
+        fv = load_vector(el, verts, lambda x: prob.f(x, t_next))
+        rhs = mass_matvec(el, jnp.asarray(state.u)) / dt + fv
+        return rhs, prob.exact(verts, t_next), _free_mask(state.mesh)
+
+    _solve_owned(session, state, rhs_g_free, 1.0 / dt)
 
 
 @register_adapt_stage("estimate", "zz")
@@ -845,11 +854,13 @@ class AdaptiveSession:
 
     # -- loop templates ----------------------------------------------------
     def _step_stationary(self, state: SessionState) -> None:
-        _ensure_elements(state)
+        with telemetry.span("adapt/elements", step=state.step):
+            _ensure_elements(state)
         self._run_stage("solve", state)
         self._run_stage("estimate", state)
-        state.err_l2 = _l2_error(state.el, state.mesh.verts, state.u,
-                                 self.problem.exact)
+        with telemetry.span("adapt/l2_error", step=state.step):
+            state.err_l2 = _l2_error(state.el, state.mesh.verts, state.u,
+                                     self.problem.exact)
         # mark + refine share the t_refine bucket (as the paper reports)
         self._run_stage("mark", state, bucket="adapt_mesh")
         self._run_stage("adapt_mesh", state)
@@ -859,10 +870,12 @@ class AdaptiveSession:
         t_next = state.t + self.spec.dt
         self._run_stage("adapt_mesh", state)      # estimate/coarsen/.../refine
         self._run_stage("transfer", state)
-        _ensure_elements(state)
+        with telemetry.span("adapt/elements", step=state.step):
+            _ensure_elements(state)
         self._run_stage("solve", state)
-        state.err_l2 = _l2_error(state.el, state.mesh.verts, state.u,
-                                 lambda x: self.problem.exact(x, t_next))
+        with telemetry.span("adapt/l2_error", step=state.step):
+            state.err_l2 = _l2_error(state.el, state.mesh.verts, state.u,
+                                     lambda x: self.problem.exact(x, t_next))
         self._run_stage("balance", state)
         state.t = t_next
 
@@ -903,7 +916,8 @@ class AdaptiveSession:
                 else:
                     self._step_timedep(state)
                 sp.set(n_tets=state.mesh.n_tets)
-            stats = self._emit_stats(state)
+            with tr.span("adapt/emit_stats", step=step):
+                stats = self._emit_stats(state)
             result.stats.append(stats)
             tr.tick(step)
             if state.repartitioned:
@@ -937,19 +951,6 @@ class AdaptiveSession:
                     "cut", unit="links",
                     help="element-adjacency links crossing parts "
                          "(paper surface index)").set(int(state.cut))
-            if (state.halo is not None and state.sharded is not None
-                    and getattr(state.sharded, "layout", "") == "owned"
-                    and state.sharded.n_interface is not None):
-                # out-of-band split-matvec phase timing (the overlapped
-                # program runs both phases concurrently); runs after the
-                # step's stage spans so t_solve is never inflated
-                from .parallel import measure_matvec_phases
-                c = (getattr(self.problem, "c", 0.0) if self.spec.stationary
-                     else 1.0 / self.spec.dt)
-                t_if, t_int = measure_matvec_phases(
-                    state.sharded, self.device_mesh, c, step=state.step)
-                state.t_matvec_halo = t_if
-                state.t_matvec_interior = t_int
         eta2 = np.asarray(state.eta, np.float64) ** 2
         tm = state.timings
         return StepStats(
@@ -967,9 +968,7 @@ class AdaptiveSession:
             migration_retained=state.migration_retained,
             t_transfer=tm.get("transfer", 0.0),
             comm_psum_bytes=state.comm_psum_bytes,
-            comm_halo_bytes=state.comm_halo_bytes,
-            t_matvec_interior=state.t_matvec_interior,
-            t_matvec_halo=state.t_matvec_halo)
+            comm_halo_bytes=state.comm_halo_bytes)
 
 
 # ---------------------------------------------------------------------------

@@ -49,8 +49,10 @@ the interior elements run -- XLA hides the exchange behind the interior
 FLOPs (``overlap=``) -- and the per-element work can dispatch to the
 fused ``kernels.fem_matvec`` element kernel (``use_pallas=`` /
 ``interpret=``, threaded from ``BalanceSpec.use_pallas`` by the
-adaptive session).  ``measure_matvec_phases`` times the two passes
-separately for ``StepStats``.
+adaptive session).  Every matvec body runs under
+``jax.named_scope(MATVEC_SCOPE)``, so each program that applies the
+operator (the PCG loop of ``jit_solve`` among them) names the matvec's
+operations alike in its op metadata, whichever path built it.
 """
 from __future__ import annotations
 
@@ -69,6 +71,8 @@ from .halo import HaloPlan, build_halo_plan, halo_reduce
 from .solve import CGResult, owned_vdot, pcg
 
 AXIS = "fem"
+# named scope of the matvec's operations in every program that applies it
+MATVEC_SCOPE = "fem_matvec"
 
 VERTEX_LAYOUTS = ("replicated", "owned")
 
@@ -251,7 +255,7 @@ def shard_elements_on_device(el: P1Elements, parts: jax.Array, p: int,
                        out_specs=(P(AXIS),) * 3)
 
     @jax.jit
-    def fn(*args):
+    def pack_elements(*args):
         st, sg, sv = packed(*args)
         return st.reshape(p, cap, 4), sg.reshape(p, cap, 4, 3), \
             sv.reshape(p, cap)
@@ -259,7 +263,7 @@ def shard_elements_on_device(el: P1Elements, parts: jax.Array, p: int,
     args = (tets, grads, vol, dest)
     if halo is not None:
         args = args + (iface, halo.global_to_local)
-    st, sg, sv = fn(*args)
+    st, sg, sv = pack_elements(*args)
     return ShardedElements(st, sg, sv, el.n_verts, p, halo=halo,
                            layout="replicated" if halo is None else "owned",
                            n_interface=n_interface)
@@ -372,9 +376,10 @@ def make_sharded_matvec(sel: ShardedElements, mesh: JMesh, c: float = 0.0,
         nv = sel.n_verts
 
         def local_apply(tets_l, grads_l, vol_l, u):
-            # (1, C, ...) block -> squeeze the part dim
-            y = element_apply(tets_l[0], grads_l[0], vol_l[0], u, nv, c)
-            return jax.lax.psum(y, AXIS)
+            with jax.named_scope(MATVEC_SCOPE):
+                # (1, C, ...) block -> squeeze the part dim
+                y = element_apply(tets_l[0], grads_l[0], vol_l[0], u, nv, c)
+                return jax.lax.psum(y, AXIS)
 
         shmap = jax.jit(shard_map(
             local_apply, mesh=mesh,
@@ -421,14 +426,15 @@ def make_sharded_matvec(sel: ShardedElements, mesh: JMesh, c: float = 0.0,
             return apply_elements(t[sl], g[sl], v[sl],
                                   None if k is None else k[sl], u)
 
-        if not overlap:
-            return halo_reduce(ap(slice(None)), send, recv, AXIS)[None]
-        # interface pass first: its partials are all the two all_to_all
-        # legs consume, so tracing it before the interior pass puts the
-        # collectives ahead of the interior FLOPs in program order --
-        # XLA overlaps the neighbor exchange with the interior elements.
-        y = halo_reduce(ap(slice(0, S)), send, recv, AXIS)
-        return (y + ap(slice(S, None)))[None]
+        with jax.named_scope(MATVEC_SCOPE):
+            if not overlap:
+                return halo_reduce(ap(slice(None)), send, recv, AXIS)[None]
+            # interface pass first: its partials are all the two all_to_all
+            # legs consume, so tracing it before the interior pass puts the
+            # collectives ahead of the interior FLOPs in program order --
+            # XLA overlaps the neighbor exchange with the interior elements.
+            y = halo_reduce(ap(slice(0, S)), send, recv, AXIS)
+            return (y + ap(slice(S, None)))[None]
 
     send_idx = jax.device_put(plan.send_idx, spec_el)
     recv_idx = jax.device_put(plan.recv_idx, spec_el)
@@ -509,63 +515,6 @@ def make_owned_operators(sel: ShardedElements, mesh: JMesh, c: float = 0.0,
                                     interpret=interpret)
     diag = sharded_diagonal(sel, mesh, c, vertex_layout="owned")
     return matvec, diag
-
-
-def measure_matvec_phases(sel: ShardedElements, mesh: JMesh, c: float = 0.0,
-                          *, u: Optional[jax.Array] = None,
-                          **attrs) -> Tuple[float, float]:
-    """Time the two phases of the split owned matvec separately.
-
-    The overlapped program runs the interface pass + halo exchange
-    concurrently with the interior pass, so their costs can only be
-    separated out of band: this runs each phase as its own jitted
-    shard_map program (compiled and warmed outside the clocks) under the
-    telemetry stopwatches ``fem/matvec_interface`` (the work the two
-    ``all_to_all`` legs wait on, plus the legs themselves) and
-    ``fem/matvec_interior`` (the FLOPs that hide them), and returns
-    ``(t_interface_s, t_interior_s)``.  The adaptive session records the
-    pair as ``StepStats.t_matvec_halo`` / ``t_matvec_interior`` when
-    tracing is on; interior >> interface is the latency-hiding headroom
-    the split exists for.  Phases run the oracle element pass -- the
-    phase *ratio*, not the kernel, is what is being measured."""
-    from .. import telemetry
-    if sel.layout != "owned" or sel.halo is None or sel.n_interface is None:
-        raise ValueError("measure_matvec_phases needs an interface-split "
-                         "owned packing")
-    plan, S = sel.halo, sel.n_interface
-    spec_el = NamedSharding(mesh, P(AXIS))
-    tets = jax.device_put(sel.tets, spec_el)
-    grads = jax.device_put(sel.grads, spec_el)
-    vol = jax.device_put(sel.vol, spec_el)
-    send_idx = jax.device_put(plan.send_idx, spec_el)
-    recv_idx = jax.device_put(plan.recv_idx, spec_el)
-    if u is None:
-        u = jnp.ones((sel.p, plan.V), sel.vol.dtype)
-    u = jax.device_put(u, spec_el)
-
-    def interface(t_l, g_l, v_l, s_l, r_l, u_l):
-        y = element_apply(t_l[0][:S], g_l[0][:S], v_l[0][:S], u_l[0],
-                          plan.V, c)
-        return halo_reduce(y, s_l[0], r_l[0], AXIS)[None]
-
-    def interior(t_l, g_l, v_l, u_l):
-        return element_apply(t_l[0][S:], g_l[0][S:], v_l[0][S:], u_l[0],
-                             plan.V, c)[None]
-
-    f_if = jax.jit(shard_map(interface, mesh=mesh,
-                             in_specs=(P(AXIS),) * 6, out_specs=P(AXIS)))
-    f_int = jax.jit(shard_map(interior, mesh=mesh,
-                              in_specs=(P(AXIS),) * 4, out_specs=P(AXIS)))
-    jax.block_until_ready(f_if(tets, grads, vol, send_idx, recv_idx, u))
-    jax.block_until_ready(f_int(tets, grads, vol, u))
-    with telemetry.stopwatch("fem/matvec_interface", n_interface=S,
-                             **attrs) as sw_if:
-        sw_if.block_on(f_if(tets, grads, vol, send_idx, recv_idx, u))
-    with telemetry.stopwatch("fem/matvec_interior",
-                             n_interior=int(sel.tets.shape[1]) - S,
-                             **attrs) as sw_int:
-        sw_int.block_on(f_int(tets, grads, vol, u))
-    return sw_if.dur_s, sw_int.dur_s
 
 
 def sharded_solve_dirichlet(sel: ShardedElements, mesh: JMesh,

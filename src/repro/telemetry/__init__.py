@@ -15,6 +15,11 @@ the balancer, the adaptive FEM loop, and the serving engine:
   ``export_jsonl`` (line-delimited event log), both schema-validated.
 * ``NullTracer`` — the process default; instrumented hot paths cost
   nothing when telemetry is off.
+* Under a real tracer every span also lands on the JAX profiler's host
+  plane (``jax.profiler.TraceAnnotation``, the device trace's clock), and
+  ``tracing()`` records each program JAX traces, lowers, compiles or
+  loads from its compile cache as a ``JitEvent`` (``tr.jit_events``,
+  totals in ``tr.jit_summary()``), attributed to the innermost span.
 
 Usage::
 
@@ -30,16 +35,16 @@ plus a serve trace under one tracer and writes/validates both artifacts.
 """
 from .metrics import (Counter, Gauge, MetricsRegistry,  # noqa: F401
                       NullMetricsRegistry)
-from .tracer import (NullTracer, Span, SpanEvent, Tracer,  # noqa: F401
-                     get_tracer, set_tracer, span, stopwatch, traced,
-                     tracing)
+from .tracer import (JIT_KINDS, JitEvent, NullTracer, Span,  # noqa: F401
+                     SpanEvent, Tracer, get_tracer, set_tracer, span,
+                     stopwatch, traced, tracing)
 from .export import (SchemaError, chrome_trace,  # noqa: F401
                      export_chrome_trace, export_jsonl, jsonl_events,
                      validate_chrome_trace, validate_jsonl)
 
 __all__ = [
     "Counter", "Gauge", "MetricsRegistry", "NullMetricsRegistry",
-    "NullTracer", "Span", "SpanEvent", "Tracer",
+    "JIT_KINDS", "JitEvent", "NullTracer", "Span", "SpanEvent", "Tracer",
     "get_tracer", "set_tracer", "span", "stopwatch", "traced", "tracing",
     "SchemaError", "chrome_trace", "export_chrome_trace", "export_jsonl",
     "jsonl_events", "validate_chrome_trace", "validate_jsonl",

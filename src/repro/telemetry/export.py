@@ -141,7 +141,8 @@ def jsonl_events(tracer) -> List[Dict[str, Any]]:
     for ev in sorted(tracer.events, key=lambda e: (e.ts_us, -e.dur_us)):
         lines.append({"type": "span", "name": ev.name,
                       "ts_us": ev.ts_us, "dur_us": ev.dur_us,
-                      "depth": ev.depth, "attrs": dict(ev.attrs)})
+                      "depth": ev.depth, "parent": ev.parent,
+                      "attrs": dict(ev.attrs)})
     for row in tracer.metrics.ticks:
         line = {"type": "counters", "step": row["step"],
                 "values": dict(row["values"])}

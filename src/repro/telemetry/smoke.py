@@ -7,8 +7,9 @@ Runs a 3-step adaptive session (sharded backend, owned vertices) and a
 short serve trace (sharded decode, KV rebalancing) under ONE tracer,
 then exports ``DIR/trace.json`` (Chrome-trace, load in Perfetto) and
 ``DIR/counters.jsonl``, validates both against their schemas, and
-asserts the trace contains a span for every registered stage and a
-counter for each of the paper's quality metrics.  Non-zero exit on any
+asserts the trace contains a span for every registered stage and layer
+boundary inside the step, a counter for each of the paper's quality
+metrics, and the programs JAX traced under the tracer.  Non-zero exit on any
 missing span/counter or schema violation — CI runs this as the
 ``telemetry-smoke`` job.
 """
@@ -25,10 +26,15 @@ if "xla_force_host_platform_device_count" not in os.environ.get(
 
 # spans expected from the adaptive session + balancer ("adapt/solve" and
 # "adapt/adapt_mesh" only appear for the stages the spec registers; the
-# smoke spec below exercises all of them) and from the serve engine
+# smoke spec below exercises all of them, and its owned layout the
+# packing and distributed-PCG spans inside them) and from the serve engine
 REQUIRED_SPANS = {
     "adapt/solve", "adapt/estimate", "adapt/mark", "adapt/adapt_mesh",
     "adapt/balance", "balance",
+    "adapt/elements", "adapt/l2_error", "adapt/emit_stats",
+    "adapt/solve/assemble", "adapt/solve/operators", "adapt/solve/pcg",
+    "adapt/solve/wait", "adapt/pack", "adapt/pack/halo_plan",
+    "adapt/pack/migrate", "adapt/pack/cut",
     "serve/prefill", "serve/decode", "serve/rebalance", "serve/run_trace",
 }
 REQUIRED_COUNTERS = {
@@ -106,6 +112,9 @@ def main(argv=None) -> int:
     if missing_counters:
         print(f"MISSING COUNTERS: {sorted(missing_counters)}",
               file=sys.stderr)
+        ok = False
+    if not any(e.kind == "jit_traces" for e in tr.jit_events):
+        print("NO JIT EVENTS recorded under the tracer", file=sys.stderr)
         ok = False
     if ok:
         print("telemetry smoke OK")

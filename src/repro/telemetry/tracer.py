@@ -21,6 +21,14 @@ Two entry points with different off-switch semantics:
   the tracer only when one is active.  Timing correctness is therefore
   independent of whether telemetry is on.
 
+While a real ``Tracer`` is active, every span (``stopwatch`` spans
+included) also opens a ``jax.profiler.TraceAnnotation`` of its own name,
+so program spans sit on the host plane of a profiler trace, on the
+device's clock; and ``tracing()`` listens to JAX's compile events
+(``jax.monitoring``), recording each top-level trace, lowering, compile
+and persistent-cache load as a ``JitEvent`` attributed to the innermost
+open span.
+
 Single-threaded by design (the control planes it instruments are); the
 span stack is per-tracer, depth/nesting come from ``with`` discipline.
 """
@@ -35,18 +43,53 @@ import jax
 
 from .metrics import MetricsRegistry, NullMetricsRegistry
 
-__all__ = ["NullTracer", "Span", "SpanEvent", "Tracer", "get_tracer",
-           "set_tracer", "span", "stopwatch", "traced", "tracing"]
+__all__ = ["JIT_KINDS", "JitEvent", "NullTracer", "Span", "SpanEvent",
+           "Tracer", "get_tracer", "set_tracer", "span", "stopwatch",
+           "traced", "tracing"]
+
+# jax.monitoring duration events -> JitEvent kinds
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+JIT_KINDS = ("jit_traces", "jit_lowerings", "jit_compiles", "jit_cache_hits")
 
 
 @dataclasses.dataclass
 class SpanEvent:
-    """One completed span, times in microseconds since the tracer epoch."""
+    """One completed span, times in microseconds since the tracer epoch;
+    ``parent`` is the name of the span that enclosed it (None at top)."""
     name: str
     ts_us: float
     dur_us: float
     depth: int
     attrs: Dict[str, Any]
+    parent: Optional[str] = None
+
+
+@dataclasses.dataclass
+class JitEvent:
+    """One program JAX traced, lowered, compiled or loaded from its
+    persistent compile cache while a tracer was active.
+
+    ``kind`` is one of ``JIT_KINDS`` (``jit_compiles`` counts programs
+    compiled afresh, ``jit_cache_hits`` those loaded from the cache);
+    ``program`` is the name the device trace gives the program
+    (``jit_<function>``); ``span`` is the innermost open span and
+    ``step`` the nearest ``step`` attribute on the span stack."""
+    kind: str
+    program: str
+    dur_s: float
+    span: Optional[str]
+    step: Optional[int]
+
+
+def _program_name(fun_name: str) -> str:
+    """``solve`` / ``jit(solve)`` -> ``jit_solve``, the name of the
+    program in the device trace."""
+    if fun_name.startswith("jit(") and fun_name.endswith(")"):
+        fun_name = fun_name[4:-1]
+    return "jit_" + fun_name
 
 
 class Span:
@@ -60,7 +103,7 @@ class Span:
     """
 
     __slots__ = ("_tracer", "name", "attrs", "_block", "_outs",
-                 "_t0", "_t1", "depth")
+                 "_t0", "_t1", "depth", "parent", "_annotation")
 
     def __init__(self, tracer: Optional["Tracer"], name: str, block: bool,
                  attrs: Dict[str, Any]):
@@ -71,6 +114,8 @@ class Span:
         self._outs: List[Any] = []
         self._t0 = self._t1 = 0.0
         self.depth = 0
+        self.parent: Optional[str] = None
+        self._annotation = None
 
     def block_on(self, value):
         """Designate ``value`` as an output to sync on before the clock
@@ -85,6 +130,9 @@ class Span:
     def __enter__(self) -> "Span":
         if self._tracer is not None:
             self.depth = self._tracer._enter(self)
+            # the same span on the profiler's host plane (device clock)
+            self._annotation = jax.profiler.TraceAnnotation(self.name)
+            self._annotation.__enter__()
         self._t0 = time.perf_counter()
         return self
 
@@ -95,6 +143,8 @@ class Span:
             jax.block_until_ready(self._outs)
         self._t1 = time.perf_counter()
         if self._tracer is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+            self._annotation = None
             self._tracer._exit(self)
         return False
 
@@ -161,7 +211,9 @@ class Tracer:
     epoch), in microseconds -- the unit Chrome-trace wants.  Spans nest
     via the ``with`` stack; ``tick(step)`` snapshots every registered
     counter/gauge with a timestamp so exporters can emit per-step
-    counter tracks.
+    counter tracks.  ``jit_events`` collects the ``JitEvent``s of every
+    ``tracing()`` scope the tracer was installed by; ``jit_summary()``
+    totals them per kind and program.
     """
 
     enabled = True
@@ -171,10 +223,15 @@ class Tracer:
         self.events: List[SpanEvent] = []
         self._stack: List[Span] = []
         self.metrics = MetricsRegistry()
+        self.jit_events: List[JitEvent] = []
+        self._listening = 0             # nested tracing() scopes
+        self._trace_depth = 0           # open jaxpr traces (nested jits)
+        self._compile_hit = False       # cache hit inside the open compile
 
     # -- span lifecycle (driven by Span.__enter__/__exit__) -----------------
     def _enter(self, sp: Span) -> int:
         depth = len(self._stack)
+        sp.parent = self._stack[-1].name if self._stack else None
         self._stack.append(sp)
         return depth
 
@@ -186,7 +243,57 @@ class Tracer:
             ts_us=(sp._t0 - self._epoch) * 1e6,
             dur_us=sp.dur_s * 1e6,
             depth=sp.depth,
-            attrs=sp.attrs))
+            attrs=sp.attrs,
+            parent=sp.parent))
+
+    # -- jax.monitoring listeners (registered by tracing()) -----------------
+    def _listen(self) -> None:
+        self._listening += 1
+        if self._listening == 1:
+            self._trace_depth = 0
+            jax.monitoring.register_scalar_listener(self._on_start)
+            jax.monitoring.register_event_duration_secs_listener(
+                self._on_duration)
+            jax.monitoring.register_event_listener(self._on_event)
+
+    def _unlisten(self) -> None:
+        self._listening -= 1
+        if self._listening == 0:
+            jax.monitoring.unregister_scalar_listener(self._on_start)
+            jax.monitoring.unregister_event_duration_listener(
+                self._on_duration)
+            jax.monitoring.unregister_event_listener(self._on_event)
+
+    def _on_start(self, event: str, value, **kw) -> None:
+        # JAX records each timed compile phase's start as a scalar
+        if event == _TRACE_EVENT:
+            self._trace_depth += 1
+        elif event == _COMPILE_EVENT:
+            self._compile_hit = False
+
+    def _on_event(self, event: str, **kw) -> None:
+        if event == _CACHE_HIT_EVENT:
+            self._compile_hit = True
+
+    def _on_duration(self, event: str, duration: float, **kw) -> None:
+        if event == _TRACE_EVENT:
+            self._trace_depth = max(self._trace_depth - 1, 0)
+            if self._trace_depth > 0:
+                return        # a jitted helper traced inside a program
+            kind = "jit_traces"
+        elif event == _LOWER_EVENT:
+            kind = "jit_lowerings"
+        elif event == _COMPILE_EVENT:
+            kind = "jit_cache_hits" if self._compile_hit else "jit_compiles"
+            self._compile_hit = False
+        else:
+            return
+        step = next((sp.attrs["step"] for sp in reversed(self._stack)
+                     if "step" in sp.attrs), None)
+        self.jit_events.append(JitEvent(
+            kind=kind, program=_program_name(str(kw.get("fun_name", "?"))),
+            dur_s=float(duration),
+            span=self._stack[-1].name if self._stack else None, step=step))
 
     # -- public API ---------------------------------------------------------
     def span(self, name: str, *, block: bool = False, **attrs) -> Span:
@@ -203,6 +310,17 @@ class Tracer:
                **attrs) -> Callable:
         """Decorator twin of ``span`` bound to THIS tracer."""
         return traced(name, block=block, tracer=self, **attrs)
+
+    def jit_summary(self) -> Dict[str, Dict[str, Dict[str, float]]]:
+        """``{kind: {program: {"n": count, "s": seconds}}}`` over
+        ``jit_events``, every kind of ``JIT_KINDS`` present."""
+        out: Dict[str, Dict[str, Dict[str, float]]] = {
+            k: {} for k in JIT_KINDS}
+        for e in self.jit_events:
+            row = out[e.kind].setdefault(e.program, {"n": 0, "s": 0.0})
+            row["n"] += 1
+            row["s"] += e.dur_s
+        return out
 
 
 # ---------------------------------------------------------------------------
@@ -226,7 +344,8 @@ def set_tracer(tracer):
 
 
 class tracing:
-    """``with tracing() as tr:`` -- install a (new) tracer for a scope."""
+    """``with tracing() as tr:`` -- install a (new) tracer for a scope and
+    record JAX's compile events into it until the scope exits."""
 
     def __init__(self, tracer: Optional[Tracer] = None):
         self.tracer = tracer if tracer is not None else Tracer()
@@ -234,9 +353,13 @@ class tracing:
 
     def __enter__(self) -> Tracer:
         self._prev = set_tracer(self.tracer)
+        if self.tracer.enabled:
+            self.tracer._listen()
         return self.tracer
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        if self.tracer.enabled:
+            self.tracer._unlisten()
         set_tracer(self._prev)
         return False
 

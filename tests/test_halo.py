@@ -426,25 +426,38 @@ def test_owned_pcg_pallas_kernel_parity(p):
     assert int(got.iters) <= int(ref.iters) + 10
 
 
-def test_measure_matvec_phases_records_spans():
-    from repro import telemetry
-    from repro.fem.parallel import (device_mesh, measure_matvec_phases,
-                                    shard_elements)
-    p = 4
-    m = _random_refined_mesh(31, levels=1)
+@pytest.mark.parametrize("path", ["overlap", "serial", "kernel",
+                                  "replicated"])
+def test_matvec_scope_names_the_matvec_in_jit_solve(path):
+    """Every program that applies the operator carries the matvec's
+    named scope in its op metadata, whichever path built the matvec: the
+    owned PCG's ``solve`` program (split, serial, kernel twin) and the
+    replicated twin."""
+    from repro.fem.parallel import (MATVEC_SCOPE, device_mesh,
+                                    make_sharded_matvec, shard_elements,
+                                    sharded_solve_dirichlet)
+    p = 2
+    m = _random_refined_mesh(41, levels=1)
     el = build_elements(m.verts, m.tets)
     parts = _partition(m, p)
-    plan = build_halo_plan(m.tets, parts, m.n_verts, p)
-    sel = shard_elements(el, parts, p, halo=plan)
-    with telemetry.tracing(telemetry.Tracer()) as tr:
-        t_if, t_int = measure_matvec_phases(sel, device_mesh(p), 1.0, step=3)
-    assert t_if > 0 and t_int > 0
-    byname = {e.name: e for e in tr.events}
-    assert byname["fem/matvec_interface"].attrs["step"] == 3
-    assert byname["fem/matvec_interface"].attrs["n_interface"] \
-        == sel.n_interface
-    assert byname["fem/matvec_interior"].attrs["n_interior"] \
-        == sel.tets.shape[1] - sel.n_interface
+    jmesh = device_mesh(p)
+    x = jnp.ones(m.n_verts, jnp.float32)
+    if path == "replicated":
+        sel = shard_elements(el, parts, p)
+        mv, _ = make_sharded_matvec(sel, jmesh, c=1.0)
+        text = jax.jit(mv).lower(x).as_text(debug_info=True)
+    else:
+        plan = build_halo_plan(m.tets, parts, m.n_verts, p)
+        sel = shard_elements(el, parts, p, halo=plan)
+
+        def solve(rhs, g, free):
+            return sharded_solve_dirichlet(
+                sel, jmesh, rhs, g, free, 1.0, overlap=path != "serial",
+                use_pallas=path == "kernel")
+        text = jax.jit(solve).lower(x, x, x).as_text(debug_info=True)
+        assert "@solve" in text
+    # a scope location, not the kernel module's file name
+    assert f'loc("{MATVEC_SCOPE}/' in text
 
 
 def test_halo_bytes_follow_solve_itemsize():

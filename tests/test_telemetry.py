@@ -337,3 +337,225 @@ def test_serve_trace_spans_and_moved_kv_counter():
     totals = tr.metrics.summary()["totals"]
     # counter total equals the migration_log the engine already keeps
     assert totals["moved_kv_bytes"] == metrics["moved_kv_bytes_total"]
+
+
+# ---------------------------------------------------------------------------
+# Profiler clock, span parents and compile events
+# ---------------------------------------------------------------------------
+
+def _host_span_names(trace_dir):
+    """Names of the events on the host planes of the profile under
+    ``trace_dir``."""
+    import glob
+    import os
+    from jax.profiler import ProfileData
+    path = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                     recursive=True)[0]
+    data = ProfileData.from_file(path)
+    return {e.name for plane in data.planes
+            if not plane.name.startswith("/device:")
+            for line in plane.lines for e in line.events}
+
+
+def test_tracer_spans_reach_the_profiler_host_plane(tmp_path):
+    f = jax.jit(lambda x: x * 2)
+    x = jnp.arange(8.0)
+    f(x).block_until_ready()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with telemetry.span("test/null_span"):
+            f(x).block_until_ready()
+        with telemetry.tracing():
+            with telemetry.span("test/outer"):
+                with telemetry.stopwatch("test/stopwatch"):
+                    f(x).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    names = _host_span_names(str(tmp_path))
+    assert {"test/outer", "test/stopwatch"} <= names
+    # telemetry off: the null span leaves nothing in the profile
+    assert "test/null_span" not in names
+
+
+def test_span_events_name_their_parent(tmp_path):
+    tr = telemetry.Tracer()
+    with telemetry.tracing(tr):
+        with telemetry.span("outer", step=4):
+            with telemetry.stopwatch("inner"):
+                pass
+            with telemetry.span("sibling"):
+                pass
+    parents = {e.name: e.parent for e in tr.events}
+    assert parents == {"outer": None, "inner": "outer", "sibling": "outer"}
+    lines = telemetry.export_jsonl(tr, str(tmp_path / "ev.jsonl"))
+    assert {ln["name"]: ln["parent"] for ln in lines
+            if ln["type"] == "span"} == parents
+
+
+def test_jit_traces_count_fresh_closures_per_call_and_cached_once():
+    """A fresh ``jax.jit`` closure traces on every call, a cached one
+    once; a jitted helper traced inside a program is not a program."""
+    @jax.jit
+    def cached_prog(x):
+        return jnp.sin(x) + 1.0
+
+    def fresh():
+        @jax.jit
+        def fresh_prog(x):
+            return jnp.cos(x) * 2.0
+        return fresh_prog
+
+    x = jnp.arange(5.0)
+    with telemetry.tracing() as tr:
+        for step in range(3):
+            with telemetry.span("test/call", step=step):
+                fresh()(x).block_until_ready()
+                cached_prog(x).block_until_ready()
+    traces = [e for e in tr.jit_events if e.kind == "jit_traces"]
+    by_prog = {}
+    for e in traces:
+        by_prog.setdefault(e.program, []).append(e)
+    assert len(by_prog["jit_fresh_prog"]) == 3
+    assert len(by_prog["jit_cached_prog"]) == 1
+    assert "jit_cos" not in by_prog and "jit_sin" not in by_prog
+    assert [e.step for e in by_prog["jit_fresh_prog"]] == [0, 1, 2]
+    assert all(e.span == "test/call" for e in traces)
+    summary = tr.jit_summary()
+    assert set(summary) == set(telemetry.JIT_KINDS)
+    assert summary["jit_traces"]["jit_fresh_prog"]["n"] == 3
+    assert summary["jit_lowerings"]["jit_fresh_prog"]["n"] == 3
+    assert summary["jit_compiles"]["jit_cached_prog"]["n"] == 1
+    assert summary["jit_traces"]["jit_cached_prog"]["s"] > 0
+
+
+def test_compile_events_are_heard_only_while_tracing():
+    x = jnp.ones(3)
+    x.block_until_ready()
+    tr = telemetry.Tracer()
+
+    def kinds():
+        return sorted(e.kind for e in tr.jit_events)
+
+    with telemetry.tracing(tr):
+        with telemetry.tracing(tr):          # nested: heard once, not twice
+            jax.jit(lambda v: v + 3.0)(x)
+        assert kinds() == ["jit_compiles", "jit_lowerings", "jit_traces"]
+        jax.jit(lambda v: v + 4.0)(x)        # the outer scope still hears
+        assert len(tr.jit_events) == 6
+    jax.jit(lambda v: v + 5.0)(x)
+    assert len(tr.jit_events) == 6           # nothing heard after the scope
+    null = telemetry.NullTracer()
+    with telemetry.tracing(null):
+        jax.jit(lambda v: v + 6.0)(x)
+    assert not hasattr(null, "jit_events")   # the null tracer hears nothing
+
+
+def test_persistent_cache_loads_count_as_cache_hits(tmp_path):
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    saved = {k: getattr(jax.config, k) for k in (
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes")}
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    cc.reset_cache()
+    try:
+        def fresh():
+            @jax.jit
+            def cache_prog(x):
+                return jnp.tanh(x) * 3.0 + 0.25
+            return cache_prog
+
+        x = jnp.arange(7.0)
+        with telemetry.tracing() as tr:
+            fresh()(x).block_until_ready()      # compiled, written
+            jax.clear_caches()
+            fresh()(x).block_until_ready()      # loaded from the cache
+        summary = tr.jit_summary()
+        assert summary["jit_compiles"]["jit_cache_prog"]["n"] == 1
+        assert summary["jit_cache_hits"]["jit_cache_prog"]["n"] == 1
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+
+
+OWNED_SPANS = {
+    "adapt/elements", "adapt/solve/assemble", "adapt/pack",
+    "adapt/pack/halo_plan", "adapt/pack/migrate", "adapt/pack/cut",
+    "adapt/solve/operators", "adapt/solve/pcg", "adapt/solve/wait",
+    "adapt/l2_error", "adapt/emit_stats",
+}
+
+
+def _owned_session(max_steps=2):
+    spec = AdaptSpec(problem="helmholtz", max_steps=max_steps,
+                     max_tets=5000, backend="sharded",
+                     vertex_layout="owned", incremental=True,
+                     balance=BalanceSpec(p=1, method="hsfc",
+                                         oneD="ksection"))
+    return AdaptiveSession(spec, devices=jax.devices()[:1])
+
+
+def test_owned_session_spans_carry_step_and_parent():
+    tr = telemetry.Tracer()
+    with telemetry.tracing(tr):
+        res = _owned_session().run(cylinder_mesh(4, 2, length=3.0,
+                                                 radius=0.5))
+    names = {e.name for e in tr.events}
+    assert OWNED_SPANS <= names
+    assert not any(n.startswith("fem/matvec") for n in names)
+    session_spans = [e for e in tr.events if e.name.startswith("adapt/")]
+    assert all(e.attrs.get("step") in range(len(res.stats))
+               for e in session_spans)
+    parents = {(e.name, e.parent) for e in tr.events}
+    for child in ("halo_plan", "migrate", "cut"):
+        assert (f"adapt/pack/{child}", "adapt/pack") in parents
+    for child in ("assemble", "operators", "pcg", "wait"):
+        assert (f"adapt/solve/{child}", "adapt/solve") in parents
+    # step 0 packs inside its solve stage; the refined mesh repacks in
+    # the balance stage
+    assert ("adapt/pack", "adapt/solve") in parents
+    assert ("adapt/pack", "adapt/balance") in parents
+    for name in ("adapt/elements", "adapt/l2_error"):
+        assert (name, "adapt/step") in parents
+    assert ("adapt/emit_stats", None) in parents
+    # every compile event lands under some span of the step
+    assert tr.jit_events
+    assert all(e.span is not None and e.step is not None
+               for e in tr.jit_events)
+    pcg = {e.program for e in tr.jit_events if e.span == "adapt/solve/pcg"}
+    assert "jit_solve" in pcg
+
+
+def test_tracer_runs_no_program_the_untraced_session_does_not():
+    """Under a Tracer the owned session lowers the same programs as
+    without one: tracing adds no device work."""
+    lowered = []
+
+    def listen(event, duration, **kw):
+        if event == "/jax/core/compile/jaxpr_to_mlir_module_duration":
+            lowered.append(kw["fun_name"])
+
+    def programs(traced):
+        jax.clear_caches()
+        lowered.clear()
+        mesh = cylinder_mesh(4, 2, length=3.0, radius=0.5)
+        if traced:
+            with telemetry.tracing():
+                _owned_session().run(mesh)
+        else:
+            _owned_session().run(mesh)
+        return sorted(lowered)
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        programs(False)                       # warm imports and host caches
+        plain, traced = programs(False), programs(True)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    assert plain and plain == traced
+    assert "jit(balance)" in plain and "jit(pack_elements)" in plain
