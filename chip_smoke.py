@@ -136,7 +136,7 @@ def phase_kernels(seed: int) -> dict:
     vol = jnp.asarray(rng.random(c) + 0.1, jnp.float32)
     u = jnp.asarray(rng.standard_normal(v + 1), jnp.float32)
     path = fem_matvec_path(v)
-    check(path == "pallas", f"matvec path {path}")
+    check(path == "window", f"matvec path {path}")
 
     def matvec(t, g, vl, x):
         return fem_matvec_op(t, g, vl, x, v, c=1.0)
@@ -155,7 +155,7 @@ def phase_adapt(p: int, devices, clock: CompileClock) -> tuple:
     from repro.core import Balancer, BalanceSpec, resolve_variants
     from repro.fem import (AdaptiveSession, AdaptSpec, build_elements,
                            cylinder_mesh, load_vector, solve_dirichlet)
-    from repro.kernels.ops import fem_matvec_path
+    from repro import telemetry
 
     t0 = time.perf_counter()
     mesh = cylinder_mesh(**MESH)
@@ -183,7 +183,9 @@ def phase_adapt(p: int, devices, clock: CompileClock) -> tuple:
 
     session = AdaptiveSession(spec, devices=devices, on_step=on_step,
                               on_stage=on_stage)
-    res = session.run(mesh)
+    # the tracer keeps the matvec's gauges of the last operator build
+    with telemetry.tracing() as tr:
+        res = session.run(mesh)
     final = res.mesh
     info = {
         "p": p, "mesh_build_s": t_mesh,
@@ -193,8 +195,8 @@ def phase_adapt(p: int, devices, clock: CompileClock) -> tuple:
                    "err_l2": s.err_l2} for s in res.stats],
         "balance_variants": resolve_variants(session.balance_spec),
         "adapt_variants": session.variants,
-        "matvec_path": fem_matvec_path(
-            res.halo.V, use_pallas=session.balance_spec.use_pallas),
+        "matvec": {k: v for k, v in tr.metrics.snapshot().items()
+                   if k.startswith("fem_matvec.")},
         "part_verts": res.halo.V,
     }
     # the owned packing is spread over all p devices

@@ -359,7 +359,7 @@ class SessionState:
     packed_version: int = -1            # mesh_version `sharded` was packed at
     packed_ntets: int = -1              # n_tets `sharded` was packed for
     balanced_step: int = -1             # step _balance_common last ran on
-    owned_ops: Dict[float, Any] = field(default_factory=dict)  # c -> (mv, diag)
+    owned_ops: Dict[float, Any] = field(default_factory=dict)  # c -> operators
     cut: Optional[int] = None           # surface index of current partition
     comm_psum_bytes: int = 0            # per-matvec comm model (owned)
     comm_halo_bytes: int = 0
@@ -501,8 +501,8 @@ def _ensure_owned_packing(session: "AdaptiveSession", state: SessionState):
 
 def _owned_operators(session: "AdaptiveSession", state: SessionState,
                      c: float):
-    """Cached (matvec, diagonal) pair for the current packing -- rebuilt
-    only when the packing itself is (``_pack_owned`` clears the cache)."""
+    """Cached ``OwnedOperators`` for the current packing -- rebuilt only
+    when the packing itself is (``_pack_owned`` clears the cache)."""
     from .parallel import make_owned_operators
     ops = state.owned_ops.get(c)
     if ops is None:

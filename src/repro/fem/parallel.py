@@ -49,13 +49,17 @@ the interior elements run -- XLA hides the exchange behind the interior
 FLOPs (``overlap=``) -- and the per-element work can dispatch to the
 fused ``kernels.fem_matvec`` element kernel (``use_pallas=`` /
 ``interpret=``, threaded from ``BalanceSpec.use_pallas`` by the
-adaptive session).  Every matvec body runs under
+adaptive session), whose per-tile vertex windows stay narrow because
+``make_owned_operators`` first puts the packing in locality order
+(``fem.order``).  Every matvec body runs under
 ``jax.named_scope(MATVEC_SCOPE)``, so each program that applies the
 operator (the PCG loop of ``jit_solve`` among them) names the matvec's
 operations alike in its op metadata, whichever path built it.
 """
 from __future__ import annotations
 
+import time
+from functools import partial
 from typing import Callable, NamedTuple, Optional, Tuple
 
 import jax
@@ -64,10 +68,15 @@ import numpy as np
 from jax.sharding import Mesh as JMesh
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from .. import telemetry
 from ..distributed.sharding import shard_map
-from ..kernels.fem_matvec import gather_corners
+from ..kernels.fem_matvec import (fem_element_matrices, fem_matvec_jnp,
+                                  fem_matvec_tiles, fem_matvec_window,
+                                  fem_matvec_windows, gather_corners)
+from ..kernels.ops import fem_matvec_path, on_tpu
 from .assemble import _HIGHEST, _MASS, P1Elements
 from .halo import HaloPlan, build_halo_plan, halo_reduce
+from .order import order_packing
 from .solve import CGResult, owned_vdot, pcg
 
 AXIS = "fem"
@@ -326,11 +335,82 @@ def element_apply(t, g, v, u, nv, c=0.0):
                                num_segments=nv)
 
 
+class MatvecPlan(NamedTuple):
+    """How the owned matvec applies its elements, decided on the host
+    from the packing's shapes when the operator is built.
+
+    ``path``: ``"window"`` (the windowed Pallas kernel), ``"xla"`` (its
+    gather/scatter twin) or ``"oracle"`` (the geometry einsum pass).
+    For the kernel, per element segment (the interface and interior
+    passes, or one pass without overlap): ``windows`` the static window
+    width ``W`` in slots and ``starts`` the (p, tiles) first window rows;
+    ``span`` the widest tile's extent of real slots."""
+    path: str
+    windows: Tuple[int, ...] = ()
+    starts: Tuple[np.ndarray, ...] = ()
+    span: int = 0
+
+
+def _segments(sel: ShardedElements, overlap: bool):
+    """Element ranges of the matvec's passes: the interface elements and
+    the interior ones with overlap, everything in one pass without."""
+    C = sel.tets.shape[1]
+    S = sel.n_interface
+    return ((0, S), (S, C)) if overlap else ((0, C),)
+
+
+def _resolve_overlap(sel: ShardedElements, overlap: Optional[bool]) -> bool:
+    if overlap is None:
+        return sel.n_interface is not None
+    if overlap and sel.n_interface is None:
+        raise ValueError("overlap needs an interface-split packing "
+                         "(repack with shard_elements*/reshard_elements, "
+                         "which set n_interface for owned layouts)")
+    return overlap
+
+
+def matvec_plan(sel: ShardedElements, *, overlap: bool,
+                use_pallas: Optional[bool] = None,
+                interpret: bool = False) -> MatvecPlan:
+    """The owned matvec's path for this packing.  ``use_pallas``
+    (``None``: on a TPU) asks for the kernel; it runs compiled on a TPU
+    where every segment's window fits the kernel's VMEM rule
+    (``kernels.ops.fem_matvec_path``), interpreted where ``interpret``
+    says so, and otherwise the twin runs.  Reads the packing's slot ids
+    on the host only where the kernel may run."""
+    if use_pallas is None:
+        use_pallas = on_tpu()
+    if not use_pallas:
+        return MatvecPlan("oracle")
+    if not (interpret or on_tpu()):
+        return MatvecPlan("xla")
+    tets = np.asarray(sel.tets)
+    V = sel.halo.V
+    wins = [fem_matvec_windows(tets[:, a:b], V)
+            for a, b in _segments(sel, overlap)]
+    windows = tuple(w for _, w, _ in wins)
+    path = fem_matvec_path(V, window=max(windows), use_pallas=True,
+                           interpret=interpret)
+    return MatvecPlan(path, windows, tuple(st for st, _, _ in wins),
+                      max(sp for _, _, sp in wins))
+
+
+@partial(jax.jit, static_argnums=(3, 4, 5))
+def _kernel_operands(tets, grads, vol, c, n_out, segments):
+    """The windowed kernel's streamed operands of each pass
+    (``fem_matvec_tiles``), from the packing in one program."""
+    kel = fem_element_matrices(grads, vol, c)
+    return tuple(fem_matvec_tiles(tets[:, a:b], kel[:, a:b], n_out)
+                 for a, b in segments)
+
+
 def make_sharded_matvec(sel: ShardedElements, mesh: JMesh, c: float = 0.0,
                         vertex_layout: Optional[str] = None, *,
                         overlap: Optional[bool] = None,
                         use_pallas: Optional[bool] = None,
-                        interpret: bool = False) -> Tuple[Callable, tuple]:
+                        interpret: bool = False,
+                        plan: Optional[MatvecPlan] = None
+                        ) -> Tuple[Callable, tuple]:
     """Returns (matvec, element arrays placed on the mesh).
 
     ``vertex_layout`` (default: the packing's own layout):
@@ -355,16 +435,16 @@ def make_sharded_matvec(sel: ShardedElements, mesh: JMesh, c: float = 0.0,
       ``halo_reduce(y_if) + y_int == halo_reduce(y_if + y_int)``.
       ``overlap=False`` forces the serial apply-everything-then-exchange
       oracle (the parity and micro-benchmark baseline).
-    ``use_pallas`` / ``interpret`` select the fused element kernel for
-      the per-element work (``kernels.fem_matvec``: precomputed 4x4
-      element matrices streamed through one launch) via the same
-      dispatch contract as every other kernel: ``None`` auto-selects on
-      TPU, ``False`` is the inline einsum oracle, ``True`` runs the
-      kernel path ``kernels.ops.fem_matvec_path`` picks from the part
-      size (compiled on TPU where the part fits the kernel's VMEM
-      budget, else the fused-XLA twin; the twin off-TPU, or the Pallas
-      interpreter when ``interpret=True``).  Kernel and oracle are
-      tolerance-exact, not bit-exact (different accumulation order).
+    ``use_pallas`` / ``interpret`` select the per-element work through
+      ``matvec_plan`` (unless ``plan`` gives it): ``None`` auto-selects
+      on TPU, ``False`` is the inline einsum oracle, ``True`` the
+      windowed kernel (``kernels.fem_matvec``: precomputed 4x4 element
+      matrices streamed through one launch per pass) where its windows
+      fit, else the fused-XLA twin; the twin off-TPU, or the Pallas
+      interpreter when ``interpret=True``.  The kernel's windows follow
+      the packing's order: ``make_owned_operators`` orders the packing
+      first (``fem.order``).  Kernel and oracle are tolerance-exact, not
+      bit-exact (different accumulation order).
     """
     layout = _resolve_layout(sel, vertex_layout)
     spec_el = NamedSharding(mesh, P(AXIS))
@@ -391,54 +471,53 @@ def make_sharded_matvec(sel: ShardedElements, mesh: JMesh, c: float = 0.0,
 
         return matvec, (tets, grads, vol)
 
-    plan = sel.halo
-    S = sel.n_interface
-    if overlap is None:
-        overlap = S is not None
-    if overlap and S is None:
-        raise ValueError("overlap needs an interface-split packing "
-                         "(repack with shard_elements*/reshard_elements, "
-                         "which set n_interface for owned layouts)")
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
-    kel = None
-    if use_pallas:
-        # per-element 4x4 operators: constant across matvecs on a fixed
-        # packing, so build once here and stream per call
-        from ..kernels.fem_matvec import fem_element_matrices
-        kel = jax.device_put(fem_element_matrices(sel.grads, sel.vol, c),
-                             spec_el)
+    halo = sel.halo
+    overlap = _resolve_overlap(sel, overlap)
+    segments = _segments(sel, overlap)
+    if plan is None:
+        plan = matvec_plan(sel, overlap=overlap, use_pallas=use_pallas,
+                           interpret=interpret)
+    # per-element 4x4 operators: constant across matvecs on a fixed
+    # packing, so build once here and stream per call
+    if plan.path == "oracle":
+        el_args = (tets, grads, vol)
+    elif plan.path == "xla":
+        kel = fem_element_matrices(sel.grads, sel.vol, c)
+        el_args = (tets, grads, vol, jax.device_put(kel, spec_el))
+    else:
+        # the kernel's tiles and windows per pass, laid out once
+        tiles = _kernel_operands(tets, grads, vol, c, halo.V, segments)
+        el_args = tuple(jax.device_put(x, spec_el) for i, st in
+                        enumerate(plan.starts) for x in tiles[i] + (st,))
 
-    def apply_elements(t, g, v, k, u):
-        if use_pallas:
-            from ..kernels import fem_matvec_op
-            return fem_matvec_op(t, g, v, u, plan.V, c=c, kel=k,
-                                 use_pallas=True, interpret=interpret)
-        return element_apply(t, g, v, u, plan.V, c)
+    def apply_elements(i, a, u):
+        # pass i of the matvec over this part's (1, ...) blocks
+        if plan.path == "window":
+            ids, ks, starts = (x[0] for x in a[3 * i:3 * i + 3])
+            return fem_matvec_window(ids, ks, starts, u, halo.V,
+                                     window=plan.windows[i],
+                                     interpret=interpret)
+        sl = slice(*segments[i])
+        t, g, v = a[0][0][sl], a[1][0][sl], a[2][0][sl]
+        if plan.path == "xla":
+            return fem_matvec_jnp(t, a[3][0][sl], u, halo.V)
+        return element_apply(t, g, v, u, halo.V, c)
 
     def local_apply_owned(*a):
-        head = 4 if kel is not None else 3
-        t, g, v = a[0][0], a[1][0], a[2][0]
-        k = a[3][0] if kel is not None else None
-        send, recv, u = a[head][0], a[head + 1][0], a[head + 2][0]
-
-        def ap(sl):
-            return apply_elements(t[sl], g[sl], v[sl],
-                                  None if k is None else k[sl], u)
-
+        send, recv, u = a[-3][0], a[-2][0], a[-1][0]
         with jax.named_scope(MATVEC_SCOPE):
             if not overlap:
-                return halo_reduce(ap(slice(None)), send, recv, AXIS)[None]
+                return halo_reduce(apply_elements(0, a, u), send, recv,
+                                   AXIS)[None]
             # interface pass first: its partials are all the two all_to_all
             # legs consume, so tracing it before the interior pass puts the
             # collectives ahead of the interior FLOPs in program order --
             # XLA overlaps the neighbor exchange with the interior elements.
-            y = halo_reduce(ap(slice(0, S)), send, recv, AXIS)
-            return (y + ap(slice(S, None)))[None]
+            y = halo_reduce(apply_elements(0, a, u), send, recv, AXIS)
+            return (y + apply_elements(1, a, u))[None]
 
-    send_idx = jax.device_put(plan.send_idx, spec_el)
-    recv_idx = jax.device_put(plan.recv_idx, spec_el)
-    el_args = (tets, grads, vol) if kel is None else (tets, grads, vol, kel)
+    send_idx = jax.device_put(halo.send_idx, spec_el)
+    recv_idx = jax.device_put(halo.recv_idx, spec_el)
     # pallas_call has no shard_map replication rule; nothing in the owned
     # region is replicated (everything is P(AXIS)), so the check is vacuous.
     # Jitted: called eagerly (the PCG lift), an un-jitted shard_map would
@@ -446,7 +525,7 @@ def make_sharded_matvec(sel: ShardedElements, mesh: JMesh, c: float = 0.0,
     shmap = jax.jit(shard_map(
         local_apply_owned, mesh=mesh,
         in_specs=(P(AXIS),) * (len(el_args) + 3), out_specs=P(AXIS),
-        check_vma=not use_pallas))
+        check_vma=plan.path == "oracle"))
 
     def matvec_owned(u):
         return shmap(*el_args, send_idx, recv_idx, u)
@@ -497,32 +576,74 @@ def sharded_diagonal(sel: ShardedElements, mesh: JMesh, c: float = 0.0,
         tets, grads, vol, send_idx, recv_idx)
 
 
+class OwnedOperators(NamedTuple):
+    """An owned packing's PCG operators and the vertex layout they act
+    in: ``halo`` is the packing's plan, or its locality-ordered
+    relabelling where the windowed kernel runs (``fem.order``)."""
+    matvec: Callable
+    diag: jax.Array
+    halo: HaloPlan
+
+
 def make_owned_operators(sel: ShardedElements, mesh: JMesh, c: float = 0.0,
                          *, overlap: Optional[bool] = None,
                          use_pallas: Optional[bool] = None,
-                         interpret: bool = False
-                         ) -> Tuple[Callable, jax.Array]:
-    """(matvec, diagonal) pair for an owned-layout packing.
+                         interpret: bool = False) -> OwnedOperators:
+    """(matvec, diagonal, layout) for an owned-layout packing.
 
     Build once per packing and reuse across solves (e.g. every time step
     between repartitions) -- the closures carry the device-placed element
     and plan arrays, so rebuilding them per call re-places and re-traces
     for nothing.  ``overlap`` / ``use_pallas`` / ``interpret`` select the
-    matvec hot path (see ``make_sharded_matvec``); the diagonal is a
-    once-per-packing setup cost and stays on the oracle pass."""
+    matvec hot path (see ``make_sharded_matvec``).  Where the kernel may
+    run, the packing is first put in locality order (``fem.order``) and
+    the kernel's windows are measured on it; if they fit, the operators
+    act in that order, else the twin runs on the packing as given.  The
+    diagonal is a once-per-packing setup cost and stays on the oracle
+    pass.
+
+    Under a telemetry tracer, sets the gauges ``fem_matvec.path``,
+    ``fem_matvec.window_slots`` (the widest pass's ``W``),
+    ``fem_matvec.tile_span_max`` and ``fem_matvec.order_s`` (host seconds
+    of the ordering)."""
+    overlap = _resolve_overlap(sel, overlap)
+    if use_pallas is None:
+        use_pallas = on_tpu()
+    order_s = 0.0
+    if use_pallas and (interpret or on_tpu()):      # the kernel may run
+        t0 = time.perf_counter()
+        ordered = order_packing(sel, mesh)
+        order_s = time.perf_counter() - t0
+        plan = matvec_plan(ordered, overlap=overlap, use_pallas=True,
+                           interpret=interpret)
+        if plan.path == "window":
+            sel = ordered
+    else:
+        plan = matvec_plan(sel, overlap=overlap, use_pallas=use_pallas,
+                           interpret=interpret)
     matvec, _ = make_sharded_matvec(sel, mesh, c, vertex_layout="owned",
-                                    overlap=overlap, use_pallas=use_pallas,
-                                    interpret=interpret)
+                                    overlap=overlap, interpret=interpret,
+                                    plan=plan)
     diag = sharded_diagonal(sel, mesh, c, vertex_layout="owned")
-    return matvec, diag
+    metrics = telemetry.get_tracer().metrics
+    metrics.gauge("fem_matvec.path",
+                  help="window, xla or oracle").set(plan.path)
+    metrics.gauge("fem_matvec.window_slots", unit="slots",
+                  help="the kernel's window W").set(max(plan.windows,
+                                                        default=0))
+    metrics.gauge("fem_matvec.tile_span_max", unit="slots",
+                  help="widest element tile's extent of slots").set(
+                      plan.span)
+    metrics.gauge("fem_matvec.order_s", unit="s",
+                  help="host seconds of the locality order").set(order_s)
+    return OwnedOperators(matvec, diag, sel.halo)
 
 
 def sharded_solve_dirichlet(sel: ShardedElements, mesh: JMesh,
                             rhs: jax.Array, g: jax.Array, free: jax.Array,
                             c: float, *, tol: float = 1e-8,
                             maxiter: int = 2000,
-                            operators: Optional[Tuple[Callable, jax.Array]]
-                            = None,
+                            operators: Optional[OwnedOperators] = None,
                             overlap: Optional[bool] = None,
                             use_pallas: Optional[bool] = None,
                             interpret: bool = False) -> CGResult:
@@ -538,24 +659,25 @@ def sharded_solve_dirichlet(sel: ShardedElements, mesh: JMesh,
     to (n_verts,).  No vertex-sized global collective anywhere in the
     iteration.
 
-    ``operators``: a prebuilt ``make_owned_operators(sel, mesh, c)``
-    pair; callers solving repeatedly on the same packing should build it
-    once and pass it in.  ``overlap`` / ``use_pallas`` / ``interpret``
-    select the matvec hot path when operators are built here (ignored
-    when ``operators`` is passed -- the prebuilt pair already chose).
+    ``operators``: prebuilt ``make_owned_operators(sel, mesh, c)``;
+    callers solving repeatedly on the same packing should build them
+    once and pass them in.  The solve runs in their layout
+    (``OwnedOperators.halo``): ``to_local`` and ``from_local`` move the
+    vectors into and out of it once per solve.  ``overlap`` /
+    ``use_pallas`` / ``interpret`` select the matvec hot path when
+    operators are built here (ignored when ``operators`` is passed --
+    the prebuilt operators already chose).
     """
     if sel.layout != "owned" or sel.halo is None:
         raise ValueError("sharded_solve_dirichlet needs an owned-layout "
                          "packing (pass halo= to the packer)")
-    plan = sel.halo
-    sharding = NamedSharding(mesh, P(AXIS))
-    owned = jax.device_put(plan.owned_mask, sharding)
-
     if operators is None:
         operators = make_owned_operators(sel, mesh, c, overlap=overlap,
                                          use_pallas=use_pallas,
                                          interpret=interpret)
-    matvec, diag_l = operators
+    matvec, diag_l, plan = operators
+    sharding = NamedSharding(mesh, P(AXIS))
+    owned = jax.device_put(plan.owned_mask, sharding)
 
     def local(a):
         return jax.lax.with_sharding_constraint(plan.to_local(a), sharding)

@@ -17,7 +17,8 @@ Kernels:
   flash_attention  blocked online-softmax attention (causal/SWA/GQA) --
                    the LM substrate's dominant compute at 32k prefill
   fem_matvec       fused P1 element matvec (gather -> precomputed-4x4
-                   apply -> scatter-accumulate as one-hot matmuls) --
+                   apply -> scatter-accumulate as one-hot matmuls over
+                   a per-tile window of a locality-ordered part) --
                    the owned-layout FEM hot path's per-call element work
   serve_prefill    segment-masked packed-prefill attention -- the serving
                    engine's batched-admission hot loop (one launch over

@@ -20,8 +20,8 @@ import jax
 import jax.numpy as jnp
 
 from . import ref as _ref
-from .fem_matvec import (BLOCK_C, fem_element_matrices, fem_matvec_jnp,
-                         fem_matvec_max_verts, fem_matvec_pallas)
+from .fem_matvec import (BLOCK_C, fem_element_matrices, fem_matvec_fits,
+                         fem_matvec_jnp, fem_matvec_pallas, lane_pad)
 from .flash_attention import flash_attention_pallas
 from .ksection_hist import (BLOCK_N, ksection_histogram_jnp,
                             ksection_histogram_pallas)
@@ -98,23 +98,28 @@ def ksection_histogram_op(keys: jax.Array, weights: jax.Array,
                                      interpret=interpret, block=block)
 
 
-def fem_matvec_path(n_out: int, *, use_pallas: Optional[bool] = None,
+def fem_matvec_path(n_out: int, *, window: Optional[int] = None,
+                    use_pallas: Optional[bool] = None,
                     interpret: bool = False, block: int = BLOCK_C) -> str:
-    """Which implementation ``fem_matvec_op`` runs for ``n_out`` slots:
-    ``"pallas"`` (the kernel: compiled on a TPU, interpreted when
-    asked), ``"xla"`` (the gather/scatter twin ``fem_matvec_jnp``) or
-    ``"oracle"`` (the geometry-recomputing reference).
+    """Which implementation runs an element matvec over ``n_out`` slots
+    whose element tiles need ``window`` slots each (default: the whole
+    lane-padded part): ``"window"`` (the kernel: compiled on a TPU,
+    interpreted when asked), ``"xla"`` (the gather/scatter twin
+    ``fem_matvec_jnp``) or ``"oracle"`` (the geometry-recomputing
+    reference).
 
     The size rule lives here: on a TPU the kernel runs only where its
-    one-hot blocks fit its VMEM budget (``fem_matvec_max_verts``);
-    larger parts run the twin on the device.  Decided from shapes, never
-    by catching a compile error."""
+    window and resident vertex blocks fit its VMEM budget
+    (``fem_matvec_fits``); otherwise the twin runs on the device.
+    Decided from shapes, never by catching a compile error."""
     if use_pallas is None:
         use_pallas = on_tpu()
     if not use_pallas:
         return "oracle"
-    if interpret or (on_tpu() and n_out <= fem_matvec_max_verts(block)):
-        return "pallas"
+    if window is None:
+        window = lane_pad(n_out)
+    if interpret or (on_tpu() and fem_matvec_fits(window, n_out, block)):
+        return "window"
     return "xla"
 
 
@@ -133,17 +138,18 @@ def fem_matvec_op(tets: jax.Array, grads: jax.Array, vol: jax.Array,
     here from (grads, vol, c) when not supplied -- callers on a fixed
     packing should precompute via ``fem_element_matrices`` and pass it)
     through the implementation ``fem_matvec_path`` picks: the compiled
-    kernel on a TPU where the part fits its VMEM budget, the Pallas
-    interpreter when ``interpret=True``, and otherwise the kernel's
-    fused-XLA twin ``fem_matvec_jnp``.  Kernel/twin vs oracle differ in
-    accumulation order: tolerance-exact, not bit-exact."""
+    kernel over the whole part (``W = Vp``) on a TPU where that fits its
+    VMEM budget, the Pallas interpreter when ``interpret=True``, and
+    otherwise the kernel's fused-XLA twin ``fem_matvec_jnp``.
+    Kernel/twin vs oracle differ in accumulation order: tolerance-exact,
+    not bit-exact."""
     path = fem_matvec_path(n_out, use_pallas=use_pallas,
                            interpret=interpret, block=block)
     if path == "oracle":
         return _ref.fem_matvec_ref(tets, grads, vol, u, n_out, c=c)
     if kel is None:
         kel = fem_element_matrices(grads, vol, c)
-    if path == "pallas":
+    if path == "window":
         return fem_matvec_pallas(tets, kel, u, n_out, interpret=interpret,
                                  block=block)
     return fem_matvec_jnp(tets, kel, u, n_out)

@@ -59,7 +59,10 @@ def chrome_trace(tracer, *, pid: int = 0, tid: int = 0) -> Dict[str, Any]:
         })
     for row in tracer.metrics.ticks:
         ts = row.get("ts_us", 0.0)
+        # a label gauge (a chosen path, say) has no counter track
         for name, value in row["values"].items():
+            if not _num(value):
+                continue
             events.append({
                 "ph": "C", "name": name, "cat": "metric",
                 "ts": ts, "pid": pid, "tid": tid,
@@ -190,9 +193,9 @@ def validate_jsonl(lines: List[Dict[str, Any]]) -> None:
             if not isinstance(values, dict):
                 raise SchemaError(f"line {i}: counters missing values")
             for k, v in values.items():
-                if not _num(v):
-                    raise SchemaError(
-                        f"line {i}: counter {k!r} value {v!r} not numeric")
+                if not (_num(v) or isinstance(v, str)):
+                    raise SchemaError(f"line {i}: counter {k!r} value "
+                                      f"{v!r} neither a number nor a label")
         elif t == "totals":
             n_totals += 1
             m = line.get("metrics")

@@ -14,7 +14,10 @@ benchmarks merge into their JSON records.
 
 Values are plain Python numbers: publishers convert device arrays with
 ``float()``/``int()`` at the boundary (they are tiny scalars, and doing
-it here keeps exports JSON-clean and bit-stable across backends).
+it here keeps exports JSON-clean and bit-stable across backends).  A
+gauge may instead hold a short ``str`` label for a state, such as the
+code path an operator build chose; the Chrome trace gives labels no counter
+track.
 """
 from __future__ import annotations
 

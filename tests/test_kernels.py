@@ -5,8 +5,10 @@ import numpy as np
 import pytest
 
 from repro.kernels import ref
-from repro.kernels.fem_matvec import (fem_element_matrices, fem_matvec_jnp,
-                                      fem_matvec_pallas)
+from repro.kernels.fem_matvec import (LANES, fem_element_matrices,
+                                      fem_matvec_jnp, fem_matvec_pallas,
+                                      fem_matvec_tiles, fem_matvec_window,
+                                      fem_matvec_windows, lane_pad)
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.ksection_hist import (ksection_histogram_jnp,
                                          ksection_histogram_pallas)
@@ -216,13 +218,91 @@ def test_fem_matvec_empty_and_padding_invisible():
     assert not np.asarray(out).any()
 
 
+def _banded_case(C, V, band, n_pad, seed, c):
+    """Elements whose slots lie within ``band`` of a sorted base slot --
+    the shape a locality-ordered packing has -- then ``n_pad`` padding
+    rows (slot ``V``, zero geometry), shuffled in or left at the end."""
+    rng = np.random.default_rng(seed)
+    base = np.sort(rng.integers(0, max(1, V - band), C))
+    tets = np.minimum(base[:, None] + rng.integers(0, band, (C, 4)), V - 1)
+    grads = rng.standard_normal((C, 4, 3)).astype(np.float32)
+    vol = rng.random(C).astype(np.float32) + 0.1
+    tets = np.concatenate([tets, np.full((n_pad, 4), V)]).astype(np.int32)
+    grads = np.concatenate([grads, np.zeros((n_pad, 4, 3), np.float32)])
+    vol = np.concatenate([vol, np.zeros(n_pad, np.float32)])
+    kel = fem_element_matrices(jnp.asarray(grads), jnp.asarray(vol), c)
+    u = jnp.asarray(rng.standard_normal(V).astype(np.float32))
+    return tets, jnp.asarray(grads), jnp.asarray(vol), kel, u
+
+
+@pytest.mark.parametrize("case", [
+    # (elements, slots, slot band, padding rows, block)
+    (1024, 256, 256, 0, 256),      # W = Vp: the whole part in one window
+    (2000, 5000, 300, 0, 256),     # W < Vp after ordering
+    (1500, 3000, 200, 211, 256),   # ragged C with padding rows at the end
+    (777, 1000, 90, 0, 128),       # first and last windows clamped at Vp
+    (300, 129, 129, 17, 128),      # Vp two rows, every window whole
+])
+@pytest.mark.parametrize("c", [0.0, 1.0])
+def test_fem_matvec_window_kernel(case, c):
+    """The windowed kernel (interpret) vs the geometry oracle: windows
+    from ``fem_matvec_windows`` hold every real slot of their tile, lie
+    inside the lane-padded part, and padding rows stay invisible."""
+    C, V, band, n_pad, block = case
+    tets, grads, vol, kel, u = _banded_case(C, V, band, n_pad, C + V, c)
+    starts, window, span = fem_matvec_windows(tets, V, block)
+    vp = lane_pad(V)
+    assert window % LANES == 0 and window <= vp
+    if band + LANES < vp // 2:
+        assert window < vp
+    assert starts.min() >= 0 and (starts * LANES + window).max() <= vp
+    t = np.concatenate([tets, np.full((-len(tets) % block, 4), V)])
+    spans = []
+    for k, tile in enumerate(t.reshape(-1, block * 4)):
+        real = tile[tile < V]
+        lo = starts[k] * LANES
+        assert ((real >= lo) & (real < lo + window)).all()
+        spans.append(real.max() - real.min() + 1 if real.size else 0)
+    assert span == max(spans)
+    ids, ks = fem_matvec_tiles(jnp.asarray(tets), kel, V, block)
+    got = fem_matvec_window(ids, ks, jnp.asarray(starts), u, V,
+                            window=window, interpret=True)
+    want = ref.fem_matvec_ref(jnp.asarray(tets), grads, vol, u, V, c=c)
+    scale = float(jnp.max(jnp.abs(want))) + 1.0
+    assert got.shape == (V,)
+    assert float(jnp.max(jnp.abs(got - want))) < 1e-4 * scale
+    # the whole-part kernel is the case W = Vp
+    whole = fem_matvec_pallas(jnp.asarray(tets), kel, u, V, interpret=True,
+                              block=block)
+    assert float(jnp.max(jnp.abs(whole - want))) < 1e-4 * scale
+
+
+def test_fem_matvec_window_gather_is_exact():
+    """With a one-hot element matrix the matvec is a pure gather and
+    scatter of single values: the three bf16 passes carry f32 through
+    unrounded, so the result equals the input to the last bit."""
+    V, C = 700, 700
+    rng = np.random.default_rng(5)
+    tets = np.stack([np.arange(C)] * 4, axis=1).astype(np.int32)
+    kel = np.zeros((C, 4, 4), np.float32)
+    kel[:, 0, 0] = 1.0                       # slot i receives u[i] once
+    u = (rng.standard_normal(V) * 10.0 ** rng.integers(-20, 20, V)).astype(
+        np.float32)
+    starts, window, _ = fem_matvec_windows(tets, V, 128)
+    ids, ks = fem_matvec_tiles(jnp.asarray(tets), jnp.asarray(kel), V, 128)
+    got = fem_matvec_window(ids, ks, jnp.asarray(starts), jnp.asarray(u), V,
+                            window=window, interpret=True)
+    assert window < lane_pad(V)
+    assert np.array_equal(np.asarray(got), u)
+
+
 def test_fem_matvec_path_off_tpu():
     """Off the TPU the matvec kernel runs only when interpreted: the
     default is the oracle, ``use_pallas=True`` the XLA twin."""
     from repro.kernels.ops import fem_matvec_path
     assert fem_matvec_path(1000) == "oracle"
     assert fem_matvec_path(1000, use_pallas=True) == "xla"
-    assert fem_matvec_path(1000, use_pallas=True, interpret=True) == "pallas"
+    assert fem_matvec_path(1000, use_pallas=True, interpret=True) == "window"
     assert fem_matvec_path(150_000, use_pallas=True) == "xla"
 
 

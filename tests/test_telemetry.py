@@ -255,6 +255,25 @@ def test_jsonl_schema_and_determinism(tmp_path):
         texport.validate_jsonl(parsed[1:])      # meta header missing
 
 
+def test_label_gauges_export_as_labels(tmp_path):
+    """A gauge holding a label (the matvec's chosen path) rides in the
+    JSONL counters and totals, and gets no Chrome counter track."""
+    tr = telemetry.Tracer()
+    tr.metrics.gauge("fem_matvec.path").set("window")
+    tr.metrics.gauge("cut").set(3)
+    tr.tick(0)
+    doc = telemetry.export_chrome_trace(tr, str(tmp_path / "trace.json"))
+    assert {e["name"] for e in doc["traceEvents"] if e["ph"] == "C"} == \
+        {"cut"}
+    lines = telemetry.export_jsonl(tr, str(tmp_path / "ev.jsonl"))
+    counters = [ln for ln in lines if ln["type"] == "counters"]
+    assert counters[0]["values"] == {"cut": 3, "fem_matvec.path": "window"}
+    assert lines[-1]["metrics"]["totals"]["fem_matvec.path"] == "window"
+    counters[0]["values"]["cut"] = [3]
+    with pytest.raises(texport.SchemaError):
+        texport.validate_jsonl(lines)
+
+
 def test_quality_counters_bit_identical_host_vs_sharded():
     """The quality counters come from one publication site fed by
     bit-exact pipelines: identical inputs => identical totals dicts."""
@@ -559,3 +578,50 @@ def test_tracer_runs_no_program_the_untraced_session_does_not():
         jax.monitoring.unregister_event_duration_listener(listen)
     assert plain and plain == traced
     assert "jit(balance)" in plain and "jit(pack_elements)" in plain
+
+
+def _owned_packing(n_axial=80):
+    from repro.fem import build_elements, build_halo_plan
+    from repro.fem.parallel import device_mesh, shard_elements
+    m = cylinder_mesh(n_axial, 2, length=4.0, radius=0.5)
+    el = build_elements(m.verts, m.tets)
+    parts = np.zeros(m.n_tets, np.int64)
+    plan = build_halo_plan(m.tets, parts, m.n_verts, 1)
+    return shard_elements(el, parts, 1, halo=plan), device_mesh(1)
+
+
+@pytest.mark.parametrize("path", ["window", "oracle"])
+def test_owned_operators_set_matvec_gauges_under_a_tracer(path):
+    """Building owned operators publishes which matvec engaged, its
+    window, the widest tile and the ordering's host seconds; the oracle
+    path orders nothing."""
+    from repro.fem.parallel import make_owned_operators
+    sel, jm = _owned_packing()
+    tr = telemetry.Tracer()
+    with telemetry.tracing(tr):
+        make_owned_operators(sel, jm, 1.0, use_pallas=path == "window",
+                             interpret=True)
+    got = {k: v for k, v in tr.metrics.snapshot().items()
+           if k.startswith("fem_matvec.")}
+    assert set(got) == {"fem_matvec.path", "fem_matvec.window_slots",
+                        "fem_matvec.tile_span_max", "fem_matvec.order_s"}
+    assert got["fem_matvec.path"] == path
+    if path == "window":
+        w = got["fem_matvec.window_slots"]
+        assert w % 128 == 0 and 0 < w < sel.halo.V
+        assert 0 < got["fem_matvec.tile_span_max"] <= w
+        assert got["fem_matvec.order_s"] > 0
+    else:
+        assert got["fem_matvec.window_slots"] == 0
+        assert got["fem_matvec.tile_span_max"] == 0
+        assert got["fem_matvec.order_s"] == 0
+
+
+def test_owned_operators_set_no_gauge_without_a_tracer():
+    from repro.fem.parallel import make_owned_operators
+    sel, jm = _owned_packing()
+    idle = telemetry.Tracer()                 # made, never installed
+    assert not telemetry.get_tracer().enabled
+    make_owned_operators(sel, jm, 1.0, use_pallas=True, interpret=True)
+    assert idle.metrics.names() == []
+    assert telemetry.get_tracer().metrics.names() == []

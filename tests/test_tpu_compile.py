@@ -19,8 +19,10 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.fem.parallel import element_apply
 from repro.kernels import ops
-from repro.kernels.fem_matvec import (fem_matvec_jnp, fem_matvec_max_verts,
-                                      fem_matvec_pallas)
+from repro.kernels.fem_matvec import (BLOCK_C, fem_matvec_fits,
+                                      fem_matvec_jnp, fem_matvec_max_verts,
+                                      fem_matvec_pallas, fem_matvec_tiles,
+                                      fem_matvec_window)
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.ksection_hist import ksection_histogram_pallas
 from repro.kernels.serve_prefill import packed_attention_pallas
@@ -83,15 +85,33 @@ def test_fem_matvec_compiles_at_largest_kernel_part(shape):
              shape((v + 1,), jnp.float32))
 
 
+def test_fem_matvec_compiles_windowed_at_cell_size(shape):
+    """The kernel the adaptive cell's solve runs: 737,700 elements of one
+    chip's part against a resident vector of ~140k slots, each tile in
+    a 1,024-slot window."""
+    c, v, w = 737_700, 140_000, 1024
+    tiles = -(-c // BLOCK_C)
+    ids, ks = jax.eval_shape(
+        lambda t, k: fem_matvec_tiles(t, k, v),
+        shape((c, 4), jnp.int32), shape((c, 4, 4), jnp.float32))
+    assert fem_matvec_fits(w, v)
+    _compile(lambda i, k, s, u: fem_matvec_window(i, k, s, u, v, window=w),
+             shape(ids.shape, ids.dtype), shape(ks.shape, ks.dtype),
+             shape((tiles,), jnp.int32), shape((v,), jnp.float32))
+
+
 def test_fem_matvec_size_rule_picks_twin_for_large_parts(monkeypatch):
-    """The dispatch as a TPU applies it: the kernel up to the largest
-    part that fits its VMEM budget, the XLA twin beyond."""
+    """The dispatch as a TPU applies it: the whole-part kernel up to the
+    largest part that fits its VMEM budget, the XLA twin beyond; a
+    narrow window keeps a large part on the kernel."""
     monkeypatch.setattr(ops, "on_tpu", lambda: True)
     v = fem_matvec_max_verts()
     assert 1000 < v < 150_000
-    assert ops.fem_matvec_path(v) == "pallas"
+    assert ops.fem_matvec_path(v) == "window"
     assert ops.fem_matvec_path(v + 1) == "xla"
     assert ops.fem_matvec_path(150_000) == "xla"
+    assert ops.fem_matvec_path(150_000, window=1024) == "window"
+    assert ops.fem_matvec_path(150_000, window=64 * 1024) == "xla"
 
 
 @pytest.mark.parametrize("impl", ["twin", "oracle"])
