@@ -8,8 +8,9 @@ Runs the cell once per seed in this one process (a short window at the
 cell's own size and load), and prints one JSON line per seed with the
 numbers the program reads against the plain reference and, for the first
 ``--control-seeds`` seeds, the numbers the control reads (the reference
-at the precision below the configuration's, in the program's place, on
-the same inputs).  The benchmark's own runs never run the control.
+at the precision below the configuration's, and the reference's partition
+of the domain under the per-axis box map, in the program's place, on the
+same inputs).  The benchmark's own runs never run the control.
 """
 from __future__ import annotations
 

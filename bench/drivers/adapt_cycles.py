@@ -21,6 +21,7 @@ import time
 import numpy as np
 
 from bench import harness
+from bench.reference import balance as ref_balance
 from bench.reference import fem as ref_fem
 from bench.reference.mesh import kuhn_cylinder
 
@@ -63,6 +64,7 @@ class Driver:
             incremental=s["incremental"],
             balance=BalanceSpec(p=len(devices), method=b["method"],
                                 oneD=b["oneD"]),
+            trigger=b["trigger"], imbalance_trigger=b["imbalance_trigger"],
             max_steps=mix["steps_per_cycle"], max_tets=s["max_tets"],
             theta=prob["theta"], tol=prob["tol"], maxiter=prob["maxiter"])
         self.ann = harness.Annotations(False)
@@ -72,18 +74,22 @@ class Driver:
 
     def _cycle(self):
         from repro.fem import AdaptiveSession
-        steps, caps = [], []
+        steps, caps, parts = [], [], []
         meshes = [(self.v0, self.t0)]
         ann = self.ann
 
         def on_stage(stage, variant, dt):
             steps[-1]["t_" + stage] = dt
+            if stage == "solve":              # the parts the solve ran on
+                parts.append(mesh.leaf_payload["parts"].copy())
             ann.switch(self.NEXT_STAGE.get(stage, "step"))
 
         def on_step(stats, state):
             caps.append({"u": state.u, "eta": state.eta,
-                         "marked": state.marked})
-            steps[-1]["cg_iters"] = stats.cg_iters
+                         "marked": state.marked, "parts": parts[-1]})
+            steps[-1].update(
+                cg_iters=stats.cg_iters, halo_bytes=stats.comm_halo_bytes,
+                migrated_frac=stats.migration_totalv / stats.n_tets)
             if len(steps) < self.spec.max_steps:
                 meshes.append((state.mesh.verts.copy(), state.mesh.tets))
                 steps.append({})
@@ -158,6 +164,8 @@ class Driver:
             if not lowp and s + 1 < len(cyc["meshes"]):
                 faults += ref_fem.refinement_faults(
                     t, marked, cyc["meshes"][s + 1][1])
+        if "part_gap" in self.cfg["limits"]:
+            out.update(self._partition_gaps(cyc, lowp))
         if lowp:
             return out
         out["refine_faults"] = float(faults)
@@ -171,3 +179,19 @@ class Driver:
             float(np.abs(np.asarray(k["u"], np.float64) - u_j).max()
                   / np.abs(u_j).max()) for k in self.cycles)
         return out
+
+    def _partition_gaps(self, cyc: dict, lowp: bool) -> dict:
+        """The parts each solve ran on against the reference's partition
+        of that step's mesh (``lowp``: the reference's partition of the
+        domain distorted by the per-axis box map, in the program's
+        place)."""
+        p = len(self.devices)
+        trigger = self.cfg["balance"]["imbalance_trigger"]
+        want = ref_balance.partitions(cyc["meshes"], p, trigger)
+        got = (ref_balance.partitions(cyc["meshes"], p, trigger,
+                                      uniform=False) if lowp
+               else [cap["parts"] for cap in cyc["caps"]])
+        return {"part_gap": max(ref_balance.part_gap(g, w, p)
+                                for g, w in zip(got, want)),
+                "imbalance_gap": max(ref_balance.imbalance(g, p)
+                                     for g in got)}

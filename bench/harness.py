@@ -200,8 +200,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     """Set up, measure, check; return the result line as a dict.
 
     ``control``: also compute the numbers of the control (the reference
-    at the precision below the configuration's) on the same inputs, under
-    ``control_checks``."""
+    at the precision below the configuration's, and the partition's
+    control) on the same inputs, under ``control_checks``."""
     from bench import trace as trace_mod
     import jax
     manifest = load_manifest(root)

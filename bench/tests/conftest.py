@@ -1,20 +1,27 @@
 """Shared set-up of the benchmark's own tests (CPU only).
 
-``tiny`` copies the benchmark's files (``BENCHMARK.json`` and the
-configurations, mixes, drivers, problems, metrics and peaks under
-``bench/``) into a temporary checkout and shrinks every configuration, so that a whole run
-of a cell takes seconds on the CPU.  Nothing here touches a TPU.
+Eight virtual CPU devices are forced before JAX starts, so that a cell
+that asks for four chips is rehearsed here as well.  ``tiny`` copies the
+benchmark's files (``BENCHMARK.json`` and the configurations, mixes,
+drivers, problems, metrics and peaks under ``bench/``) into a temporary
+checkout and shrinks every configuration by the ``tiny`` object in its
+own file, so that a whole run of a cell takes seconds on the CPU.
+Nothing here touches a TPU.
 """
 import json
+import os
 import shutil
 from pathlib import Path
 
-import pytest
+_FLAG = "--xla_force_host_platform_device_count=8"
+if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS",
+                                                                ""):
+    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") + " "
+                               + _FLAG).strip()
+
+import pytest  # noqa: E402
 
 REPO = Path(__file__).resolve().parents[2]
-TINY = {
-    "helmholtz-cyl": lambda c: c["mesh"].update(n_axial=8, n_cross=2),
-}
 
 
 def copy_bench(dst: Path) -> Path:
@@ -25,14 +32,26 @@ def copy_bench(dst: Path) -> Path:
     return dst
 
 
+def merge(base: dict, over: dict) -> dict:
+    """``base`` with ``over``'s entries, nested objects merged key by key."""
+    out = dict(base)
+    for key, value in over.items():
+        out[key] = (merge(out[key], value) if isinstance(value, dict)
+                    and isinstance(out.get(key), dict) else value)
+    return out
+
+
+def shrink(root: Path) -> None:
+    """Give every configuration under ``root`` its own ``tiny`` sizes."""
+    for path in (root / "bench" / "configs").glob("*.json"):
+        cfg = json.loads(path.read_text())
+        path.write_text(json.dumps(merge(cfg, cfg["tiny"])))
+
+
 @pytest.fixture
 def tiny(tmp_path) -> Path:
     root = copy_bench(tmp_path)
-    for name, shrink in TINY.items():
-        path = root / "bench" / "configs" / f"{name}.json"
-        cfg = json.loads(path.read_text())
-        shrink(cfg)
-        path.write_text(json.dumps(cfg))
+    shrink(root)
     return root
 
 

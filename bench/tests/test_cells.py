@@ -27,7 +27,8 @@ def test_tiny_run_is_correct(tiny, cell):
                                                     "end_to_end")}
     assert set(out["metrics"]) == want
     assert all(m["value"] > 0 for m in out["metrics"].values())
-    assert out["device"]["count"] == 1
+    assert out["device"]["count"] == harness.find(
+        manifest["workloads"], cell, "cell")["chips"]
 
 
 @pytest.mark.parametrize("cell", CELLS)

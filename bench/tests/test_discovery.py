@@ -7,6 +7,7 @@ import shutil
 import pytest
 
 from bench import harness
+from bench.tests.conftest import REPO, shrink
 
 DRIVER = '''
 import time
@@ -85,6 +86,48 @@ def test_added_config_problem_mix_and_metric_are_found(tiny):
                              root=tiny, bench=bench, require_tpu=False)
     assert set(plain["metrics"]) == {"adapt_step_s", "setup_s"}
     assert plain["correct"] and plain["attempted"] >= 1
+
+
+def test_added_config_and_four_chip_cell_are_found(tiny):
+    """What the next configuration brings: its file at full size with
+    ``tiny`` sizes of its own, and a cell on four chips appended to the
+    lists of the metrics it reports; no file that was there changes."""
+    bench = tiny / "bench"
+    cfg = json.loads((REPO / "bench" / "configs"
+                      / "helmholtz-cyl-p4.json").read_text())
+    cfg["name"] = "helmholtz-thick"
+    cfg["mesh"]["radius"] = 0.75
+    cfg["tiny"] = {"mesh": {"n_axial": 6, "n_cross": 2}}
+    path = bench / "configs" / "helmholtz-thick.json"
+    path.write_text(json.dumps(cfg))
+    shrink(tiny)
+    assert json.loads(path.read_text())["mesh"]["n_axial"] == 6
+    cell = "helmholtz-thick.adapt"
+    add(tiny,
+        configs=[{"name": "helmholtz-thick",
+                  "source": "https://arxiv.org/abs/1702.00312",
+                  "file": "bench/configs/helmholtz-thick.json",
+                  "reduced": [], "why": "an added configuration"}],
+        workloads=[{"name": cell, "config": "helmholtz-thick",
+                    "traffic": "adapt", "chips": 4,
+                    "why": "an added four-chip cell"}])
+    manifest = json.loads((tiny / "BENCHMARK.json").read_text())
+    for m in manifest["per_layer"]:
+        if "helmholtz-cyl-p4.adapt" in m.get("workloads", []):
+            m["workloads"].append(cell)
+    (tiny / "BENCHMARK.json").write_text(json.dumps(manifest))
+    plain = harness.run_cell(cell, 2**40 + 3, 0.01, False, root=tiny,
+                             bench=bench, require_tpu=False)
+    assert plain["correct"], plain["checks"]
+    assert plain["device"]["count"] == 4
+    assert set(plain["metrics"]) == {"adapt_step_s", "setup_s"}
+    assert {"part_gap", "imbalance_gap"} <= set(plain["checks"])
+    traced = harness.run_cell(cell, 2**40 + 4, 0.01, True, root=tiny,
+                              bench=bench, require_tpu=False)
+    assert traced["correct"], traced["checks"]
+    assert {"halo_bytes.adapt", "migrated_frac.adapt", "cg_iters.adapt",
+            "balance_stage_s.adapt"} <= set(traced["metrics"])
+    assert traced["metrics"]["halo_bytes.adapt"]["value"] > 0
 
 
 def test_added_driver_is_found(tiny):

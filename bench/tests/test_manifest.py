@@ -70,6 +70,18 @@ def test_configs_files_and_use():
         assert all(NAME.match(k) for k in c["reduced"])
 
 
+@pytest.mark.parametrize("config", MANIFEST["configs"],
+                         ids=lambda c: c["name"])
+def test_config_has_tiny_sizes(config):
+    """The CPU tests run a configuration at the sizes of its own ``tiny``
+    object, never at its full size."""
+    body = json.loads((REPO / config["file"]).read_text())
+    tiny = body.get("tiny")
+    assert isinstance(tiny, dict) and tiny, \
+        f"{config['name']}: no tiny sizes for the CPU tests"
+    assert set(tiny) <= set(body) - {"tiny"}, config["name"]
+
+
 def test_cells():
     configs = {c["name"] for c in MANIFEST["configs"]}
     cells = MANIFEST["workloads"]
